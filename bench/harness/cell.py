@@ -1,0 +1,311 @@
+"""One run of one cell: set-up, warm-up, the measured window, the check.
+
+``run`` drives the discovery service as a user would: it ingests the
+seeded lake through ``DiscoveryService.add``, flushes it to the device,
+warms every shape the cell's traffic uses, measures for ``seconds``,
+reads the device's peak memory, frees the service and then holds the
+window's answers against the plain reference.
+"""
+
+from __future__ import annotations
+
+import gc
+import importlib.util
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+from harness import compare, reference, tracing, traffic
+from harness.lake import Lake
+from harness.peaks import peaks
+
+INGEST_CHUNK = 2048
+
+
+class NoChip(RuntimeError):
+    pass
+
+
+def log(*parts) -> None:
+    print(*parts, file=sys.stderr, flush=True)
+
+
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def cell_spec(root: str, bench: dict, name: str) -> tuple[dict, dict, dict]:
+    """(cell, configuration, traffic mix) of workload ``name``, found by
+    name: the configuration's ``file`` and ``bench/traffic/<mix>.json``."""
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json")
+    cell = cells[name]
+    cfgs = {c["name"]: c for c in bench["configs"]}
+    cfg = load_json(os.path.join(root, cfgs[cell["config"]]["file"]))
+    spec = traffic.load(os.path.join(root, "bench", "traffic",
+                                     cell["traffic"] + ".json"))
+    return cell, cfg, spec
+
+
+def cell_metrics(bench: dict, cell: dict, trace: bool) -> list[dict]:
+    """The metrics a run of ``cell`` reports: end-to-end ones with the
+    trace off, per-layer ones with it on."""
+    name = cell["name"]
+
+    def listed(m):
+        return name in m.get("workloads", [name])
+
+    e2e = [m for m in bench["end_to_end"] if listed(m)]
+    if not trace:
+        return e2e
+    moved = {m["name"] for m in e2e}
+
+    def reports(m):
+        if "workloads" in m:
+            return name in m["workloads"]
+        return m["moves"] in moved
+
+    return [m for m in bench["per_layer"] if reports(m)]
+
+
+def load_reader(root: str, metric: str):
+    path = os.path.join(root, "bench", "metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+# ---------------------------------------------------------------------------
+# set-up
+# ---------------------------------------------------------------------------
+
+def ingest(svc, lake: Lake) -> float:
+    """Add every column of the lake; the next chunk is generated on a
+    second thread while this one is added."""
+    C = lake.columns
+    t = time.perf_counter()
+    bounds = [(a, min(a + INGEST_CHUNK, C)) for a in range(0, C, INGEST_CHUNK)]
+    with ThreadPoolExecutor(1) as pool:
+        nxt = pool.submit(lake.chunk, *bounds[0])
+        for i, (a, b) in enumerate(bounds):
+            ch = nxt.result()
+            if i + 1 < len(bounds):
+                nxt = pool.submit(lake.chunk, *bounds[i + 1])
+            keys, vf, vc = ch["keys"], ch["vals_f"], ch["vals_c"]
+            for j, c in enumerate(range(a, b)):
+                disc = bool(lake.discrete[c])
+                svc.add(f"c{c}", "k", "v", keys[j], vc[j] if disc else vf[j],
+                        disc)
+    return time.perf_counter() - t
+
+
+def flush(svc) -> float:
+    import jax
+    t = time.perf_counter()
+    for y_disc in (False, True):
+        plan = svc.index.plan(y_disc)
+        jax.block_until_ready([g.arrays for g in plan.groups])
+    return time.perf_counter() - t
+
+
+# ---------------------------------------------------------------------------
+# the run
+# ---------------------------------------------------------------------------
+
+class Stage:
+    """A service with the cell's lake ingested and its queries sketched."""
+
+
+def set_up(root: str, bench: dict, name: str, *, seed: int,
+           require_chip: bool = True, service_factory=None) -> Stage:
+    """Everything before warm-up: device check, lake, ingest, flush, the
+    query sketches the traffic will send."""
+    import jax
+    from repro.core.discovery import DiscoveryService
+    from repro.core.sketch import build_sketch
+
+    st = Stage()
+    st.cell, st.cfg, st.spec = cell_spec(root, bench, name)
+    cfg, spec = st.cfg, st.spec
+    st.devices = jax.devices()
+    dev = st.devices[0]
+    if require_chip and (dev.platform != "tpu"
+                         or len(st.devices) < int(st.cell["chips"])):
+        raise NoChip(f"cell {name} needs {st.cell['chips']} TPU chip(s); "
+                     f"JAX found {len(st.devices)} {dev.platform} device(s)")
+    st.device = {"platform": dev.platform, "kind": dev.device_kind,
+                 "count": len(st.devices)}
+    log(f"phase=devices platform={dev.platform} kind={dev.device_kind!r} "
+        f"count={len(st.devices)} jax={jax.__version__}")
+    st.compiles = traffic.Compiles()
+    st.seed = seed
+    n = st.n = int(cfg["n"])
+    st.opts = traffic.serving_options(spec)
+
+    st.lake = Lake(cfg, seed)
+    st.lake.keep_rows()
+    make = service_factory or DiscoveryService
+    st.svc = make(n=n, k=int(cfg["k"]), sig_width=int(cfg["sig_width"]),
+                  agg=cfg["agg"], method=cfg["method"])
+    st.ingest_s = ingest(st.svc, st.lake)
+    log(f"phase=ingest columns={len(st.svc)} seconds={st.ingest_s:.3f}")
+    st.flush_s = flush(st.svc)
+    log(f"phase=flush seconds={st.flush_s:.3f}")
+
+    def sketch(q):
+        return build_sketch(q.keys, q.y, n=n, method=cfg["method"],
+                            side="train", value_is_discrete=q.discrete)
+
+    st.requests = traffic.requests(st.lake, spec)
+    st.req_sk = [[sketch(q) for q in r] for r in st.requests]
+    st.warm_sk = [[sketch(q) for q in r]
+                  for r in traffic.requests(st.lake, spec, warm=True)[:4]]
+    return st
+
+
+def warm_up(st: Stage) -> int:
+    """Run the cell's own traffic until it compiles nothing more."""
+    return traffic.warm("submit", lambda i: st.svc.submit(
+        st.warm_sk[i % len(st.warm_sk)], **st.opts), st.compiles,
+        min_calls=4, log=log)
+
+
+def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
+        trace: bool, t_start: float, require_chip: bool = True,
+        service_factory=None) -> dict:
+    import jax
+
+    st = set_up(root, bench, name, seed=seed, require_chip=require_chip,
+                service_factory=service_factory)
+    cell, cfg, spec, lake = st.cell, st.cfg, st.spec, st.lake
+    svc, compiles, device, n = st.svc, st.compiles, st.device, st.n
+    calls = warm_up(st)
+    t_window = time.perf_counter()
+    setup_s = t_window - t_start
+    log(f"phase=setup seconds={setup_s:.3f} warmup_calls={calls}")
+
+    # -- the measured window --------------------------------------------
+    trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
+    before = compiles.n
+    if trace:
+        jax.profiler.start_trace(trace_dir)
+    t_w = time.perf_counter()
+    out = traffic.run_closed(lambda sk: svc.submit(sk, **st.opts),
+                             st.req_sk, seconds)
+    window_s = time.perf_counter() - t_w
+    if trace:
+        jax.profiler.stop_trace()
+    window_compiles = compiles.n - before
+    print(f"window_compiles={window_compiles} window_s={window_s:.3f}",
+          flush=True)
+    log(f"phase=window compiles={window_compiles} seconds={window_s:.3f}")
+
+    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in st.devices[:int(cell["chips"])])
+    device["memory_peak_bytes"] = int(peak)
+    del svc, st.svc
+    gc.collect()
+
+    # -- end-to-end metrics ---------------------------------------------
+    done = out["done"]
+    T = len(spec["targets"])
+    attempted = len(done) * T
+    pairs = attempted * lake.columns
+    metrics = {"setup_s": {"value": setup_s, "unit": "s"},
+               "screen_pairs_per_s": {
+                   "value": pairs / (out["t_end"] - out["t0"]),
+                   "unit": "pairs/s"}}
+    log(f"phase=screen requests={len(done)} pairs={pairs}")
+
+    # -- the check --------------------------------------------------------
+    t_ref = time.perf_counter()
+    ok, checks, lanes = check(st, done, trace)
+    log(f"phase=reference seconds={time.perf_counter() - t_ref:.3f}")
+
+    result = {"correct": ok, "attempted": int(attempted), "failed": 0}
+    wanted = cell_metrics(bench, cell, trace)
+    if trace:
+        events = tracing.read_events(tracing.find_xplane(trace_dir))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        red = tracing.reduce(events) if events["devices"] else None
+        if red is not None:
+            device["busy_s"] = red["busy_s"]
+            device["window_s"] = window_s
+        ctx = {"cell": cell, "config": cfg, "traffic": spec,
+               "ingest_s": st.ingest_s, "columns": lake.columns,
+               "flush_s": st.flush_s, "events": events, "trace": red,
+               "window_s": window_s, "queries": attempted,
+               "knn_lanes": lanes, "P": n,
+               "peaks": peaks(device["kind"])
+               if device["platform"] == "tpu" else None}
+        metrics = {}
+        for m in wanted:
+            v = load_reader(root, m["name"])(ctx)
+            if v is not None:
+                metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+        if red is not None:
+            result["breakdown"] = {"device_ops": red["device_ops"],
+                                   "idle_gaps": red["idle_gaps"]}
+    else:
+        names = {m["name"] for m in wanted}
+        metrics = {k: v for k, v in metrics.items() if k in names}
+    result["metrics"] = metrics
+    result["device"] = device
+    result["checks"] = checks
+    return result
+
+
+# ---------------------------------------------------------------------------
+# the check
+# ---------------------------------------------------------------------------
+
+def check(st: Stage, done: list, count_lanes: bool):
+    """Hold the window's answers against the reference: every served
+    entry of every completed request (its join size, its MI, the
+    order and length of its list), and, on ``complete_checks`` requests
+    drawn from the seed, every passing column the list may have missed.
+    Returns (correct, checks, kNN lanes by estimator kind or None)."""
+    lake, spec, n, k = st.lake, st.spec, st.n, int(st.cfg["k"])
+    mj, top_k = st.opts["min_join"], st.opts["top_k"]
+    ls = reference.LakeSketches(lake, n)
+    joins: dict = {}
+    R = len(st.requests)
+    rng = np.random.default_rng([int(st.seed) % (1 << 63), 7])
+    full = set(rng.choice(len(done), size=min(len(done),
+                          int(spec["complete_checks"])), replace=False)
+               .tolist())
+    gap, wrong, entries = 0.0, 0, 0
+    for i, (req, res) in enumerate(done):
+        for q, served in zip(st.requests[req % R], res):
+            only = None if i in full else                 [compare.column_of(t) for t, _, _ in served]
+            ans = reference.answer(lake, ls, q, n=n, k=k, min_join=mj,
+                                   only=only, joins=joins)
+            if ans.skipped:
+                continue
+            g, w = compare.check_answer(served, ans, top_k=top_k,
+                                        min_join=mj)
+            gap, wrong, entries = max(gap, g), wrong + w, entries + len(served)
+    lanes = None
+    if count_lanes:
+        lanes = {}
+        for req, _ in done:
+            for q in st.requests[req % R]:
+                _, _, _, js = reference.join(ls, q, n, joins)
+                for disc in (False, True):
+                    kind = reference.kind_of(disc, q.discrete)
+                    cnt = int(((js >= mj) & (lake.discrete == disc)).sum())
+                    lanes[kind] = lanes.get(kind, 0) + cnt
+    ok, checks = compare.verdict(gap, wrong)
+    log(f"phase=check requests={len(done)} complete={len(full)} "
+        f"served_entries={entries}")
+    return ok, checks, lanes

@@ -1,0 +1,101 @@
+"""The trace reduction, the kernel work count and the peaks table."""
+
+import pytest
+
+import tinybench  # noqa: F401  (puts bench/ and src/ on the path)
+from harness import peaks, tracing, work
+
+
+def _events(devs, host=()):
+    return {"devices": devs, "host": list(host)}
+
+
+def test_busy_is_the_union_of_overlapping_ops():
+    ev = _events({"/device:TPU:0": [("a", 0, 100, "a"), ("b", 50, 150, "b"),
+                                    ("c", 300, 400, "c | x"),
+                                    ("d", 310, 320, "d")]},
+                 host=[("outer", 0, 1000), ("collect", 160, 290)])
+    red = tracing.reduce(ev)
+    assert red["busy_s"] == pytest.approx(250e-9)
+    assert red["device_ops"][0] == ["a", pytest.approx(100e-9)]
+    # The one gap (150, 300) is named by the innermost host event over it.
+    assert red["idle_gaps"] == [["collect", pytest.approx(150e-9)]]
+    assert tracing.kernel_seconds(ev, ("b", "d")) == pytest.approx(110e-9)
+    assert tracing.kernel_seconds(ev, ("x",)) == pytest.approx(100e-9)
+
+
+def test_busy_is_averaged_over_chips():
+    ev = _events({"/device:TPU:0": [("a", 0, 100, "a")],
+                  "/device:TPU:1": [("a", 0, 300, "a")]})
+    assert tracing.reduce(ev)["busy_s"] == pytest.approx(200e-9)
+
+
+# A small trace in the profiler's own format (an XSpace): one chip whose
+# "XLA Ops" line holds two fusions and a Mosaic kernel that overlaps the
+# first, and a host thread.  Times in picoseconds from 1000 ns.
+XSPACE = """
+planes {
+  id: 1
+  name: "/device:TPU:0"
+  lines {
+    id: 1
+    name: "XLA Ops"
+    timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 100000 }
+    events { metadata_id: 2 offset_ps: 50000 duration_ps: 100000
+             stats { metadata_id: 1 str_value: "custom_call_target=tpu_custom_call" } }
+    events { metadata_id: 1 offset_ps: 300000 duration_ps: 100000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "fusion.1" } }
+  event_metadata { key: 2 value { id: 2 name: "custom-call.7" } }
+  stat_metadata { key: 1 value { id: 1 name: "long_name" } }
+}
+planes {
+  id: 2
+  name: "/host:CPU"
+  lines {
+    id: 1
+    name: "python"
+    timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 2 offset_ps: 160000 duration_ps: 130000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "outer" } }
+  event_metadata { key: 2 value { id: 2 name: "collect" } }
+}
+"""
+
+
+def test_trace_file_reduction(tmp_path):
+    from jax.profiler import ProfileData
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(XSPACE))
+    assert tracing.find_xplane(str(tmp_path)) == str(path)
+    ev = tracing.read_events(str(path))
+    assert list(ev["devices"]) == ["/device:TPU:0"]
+    red = tracing.reduce(ev)
+    assert red["busy_s"] == pytest.approx(250e-9)
+    assert red["device_ops"][0] == ["fusion.1", pytest.approx(200e-9)]
+    assert red["idle_gaps"] == [["collect", pytest.approx(150e-9)]]
+    assert tracing.kernel_seconds(ev, ("tpu_custom_call",)) == \
+        pytest.approx(100e-9)
+
+
+def test_kernel_work_count():
+    assert work.lane_work("mixed", 256) == (15 * 256 * 256, 9 * 4 * 256)
+    assert work.lane_work("dc_x", 1024) == (6 * 1024 ** 2, 6 * 4 * 1024)
+    assert work.lane_work("mle", 256) == (0.0, 0.0)
+    pk = peaks.peaks("TPU v5 lite")
+    lanes = {"mixed": 1000, "dc_y": 500, "mle": 9999}
+    f = 1000 * 15 * 256 ** 2 + 500 * 6 * 256 ** 2
+    b = 1000 * 9 * 4 * 256 + 500 * 6 * 4 * 256
+    least = max(f / pk["flops_per_s"], b / pk["hbm_bytes_per_s"])
+    assert work.roofline_pct(lanes, 256, least, pk) == pytest.approx(100.0)
+    assert work.roofline_pct(lanes, 256, 10 * least, pk) == pytest.approx(10.0)
+    assert work.roofline_pct(lanes, 256, 0.0, pk) is None
+    assert work.roofline_pct({"mle": 5}, 256, 1.0, pk) is None
+
+
+def test_unknown_device_kind_raises():
+    with pytest.raises(KeyError):
+        peaks.peaks("TPU v99")
