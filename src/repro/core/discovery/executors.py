@@ -77,6 +77,14 @@ The estimator-id -> estimator mapping lives in exactly one place
 (:func:`_estimate`); the legacy switch scorer (`score_batch`), the seed
 reference (`score_batch_reference`), and every partitioned program
 dispatch through it, so they cannot drift apart.
+
+**Stage names.**  The shared bodies trace under ``jax.named_scope``s
+(:func:`_stage`), so every program above — dense, host-boundary, fused,
+tiered, mesh — names its stages alike in its op metadata and in a
+profiler trace: ``discovery.gate`` (phase-0 containment gate),
+``discovery.prefilter`` (join sizes), ``discovery.compact`` (shortlist
+compaction), ``discovery.score`` (row gather and estimators, the
+``knn_stats`` kernel included) and ``discovery.topk`` (device merges).
 """
 
 from __future__ import annotations
@@ -235,6 +243,22 @@ def _score_pair(tk, tf, tu, tm, ck, cf, cu, cm, *, est_id: int, k: int):
 _STEP_ELEMS = 1 << 19
 
 
+def _stage(name: str):
+    """Trace the decorated body under ``jax.named_scope(name)``, so every
+    device op it emits carries ``name`` in its op metadata (and in the
+    profiler's ``tf_op`` stat) whichever program inlines it.  A fresh
+    scope per call: one ``named_scope`` object used as a decorator keeps
+    its saved name stack on itself, which two threads tracing at once
+    would overwrite.  Metadata only: no numerics or fusion change."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return traced
+    return wrap
+
+
 def _lane_step(q: int, n: int, p: int) -> int:
     """Candidate lanes per step for q queries over n lanes of P-row
     samples: the largest power of two that divides n and keeps
@@ -243,6 +267,7 @@ def _lane_step(q: int, n: int, p: int) -> int:
     return min(n & -n, 1 << (budget.bit_length() - 1))
 
 
+@_stage("discovery.score")
 def _score_lanes(train, cands, rows=None, *, est_id: int, k: int):
     """(Q, n) MI and join sizes of Q train sketches against candidates.
 
@@ -349,6 +374,7 @@ def _score_group_many(
 # ---------------------------------------------------------------------------
 
 
+@_stage("discovery.prefilter")
 def _join_sizes_impl(train_keys, train_mask, cand_keys, cand_mask,
                      rows=None):
     """(Q, n) join sizes: every query against every candidate row, or
@@ -421,6 +447,7 @@ def _gather_score_group(
 
 
 @jax.jit
+@_stage("discovery.score")
 def _gather_shortlist(keys, vals_f, vals_u, mask, rows):
     """Device gather of shortlist rows into a compact (Q, S, cap) batch
     (the mesh phase-2 operand — ``shard_map`` then shards the S axis)."""
@@ -446,6 +473,7 @@ def _prefix_count(passing):
     return (blk + off[:, :, None]).reshape(Q, n)
 
 
+@_stage("discovery.compact")
 def _compact_shortlist(js, live, min_join, sentinel, index, s_bucket: int):
     """Device shortlist compaction — the fused replacement for the host
     :func:`~repro.core.discovery.planner.build_shortlists` boundary.
@@ -518,6 +546,7 @@ def _fused_score_group(
 # ---------------------------------------------------------------------------
 
 
+@_stage("discovery.gate")
 def _containment_gate_impl(
     train_keys, train_mask, sig, live, min_cont, *, s_surv: int,
 ):
@@ -603,22 +632,24 @@ def _tiered_pipeline_impl(
     )
     js = _join_sizes_impl(train_keys, train_mask, cand_keys, cand_mask,
                           rows0)
-    passing = (js >= min_join) & live0
-    cum = _prefix_count(passing)
-    counts1 = cum[:, -1]
-    lanes = jnp.arange(1, s_bucket + 1, dtype=jnp.int32)
-    pos_raw = jax.vmap(
-        lambda cs: jnp.searchsorted(cs, lanes, side="left")
-    )(cum)
-    lane_live = (
-        jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < counts1[:, None]
-    )
-    pos = jnp.where(lane_live, pos_raw.astype(jnp.int32), 0)
-    rows = jnp.take_along_axis(rows0, pos, axis=1)
-    gidx = jnp.where(lane_live, index[rows], sentinel)
-    jsz = jnp.where(
-        lane_live, jnp.take_along_axis(js, pos, axis=1), 0
-    )
+    with jax.named_scope("discovery.compact"):
+        passing = (js >= min_join) & live0
+        cum = _prefix_count(passing)
+        counts1 = cum[:, -1]
+        lanes = jnp.arange(1, s_bucket + 1, dtype=jnp.int32)
+        pos_raw = jax.vmap(
+            lambda cs: jnp.searchsorted(cs, lanes, side="left")
+        )(cum)
+        lane_live = (
+            jnp.arange(s_bucket, dtype=jnp.int32)[None, :]
+            < counts1[:, None]
+        )
+        pos = jnp.where(lane_live, pos_raw.astype(jnp.int32), 0)
+        rows = jnp.take_along_axis(rows0, pos, axis=1)
+        gidx = jnp.where(lane_live, index[rows], sentinel)
+        jsz = jnp.where(
+            lane_live, jnp.take_along_axis(js, pos, axis=1), 0
+        )
     mi, _ = _score_lanes(
         (train_keys, train_vals_f, train_vals_u, train_mask),
         (cand_keys, cand_vals_f, cand_vals_u, cand_mask), rows,
@@ -1674,6 +1705,7 @@ def compile_count() -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("k_shard", "shard_rows"))
+@_stage("discovery.topk")
 def _globalize_rows(i, index_dev, *, k_shard: int, shard_rows: int):
     """Map per-shard top-k row indices (Q, shards·k_shard) to global
     candidate indices on device: undo the shard-local numbering, then
@@ -1697,6 +1729,7 @@ def _concat1(xs):
 
 
 @functools.partial(jax.jit, static_argnames=("k_final",))
+@_stage("discovery.topk")
 def _merge_topk_device(v, gi, js, *, k_final: int):
     """Cross-group merge on device: one ``lax.top_k`` over the
     concatenated per-group/per-shard winners, all Q rows at once.  The

@@ -77,6 +77,19 @@ silently ranked.  Stats discipline in both surfaces: arrival counters
 a raise mid-submit can no longer leave ``stats()`` claiming work that
 never delivered.  Failures are counted explicitly
 (``failed_buckets``/``retries``/``fallbacks``/``lost_queries``).
+
+**Tracing**: the layer boundaries that the synchronous and scheduler
+surfaces share are ``jax.profiler.TraceAnnotation`` spans, so under a
+profiler session they land on the device trace's clock:
+``discovery.submit`` (a whole synchronous submit),
+``discovery.dispatch`` (one per bucket: plan-cache lookup, train
+staging, the enqueue; the host-boundary path adds one for its phase-2
+dispatch), ``discovery.collect`` (one per bucket: the host
+sync and the overflow fence) and ``discovery.rank`` (one per bucket:
+fencing, ranking, the stat commit).  Each carries ``window`` (the
+``submits`` count that admitted it) and, below submit, ``bucket`` (the
+job's index in its window).  With no profiler session a span costs a
+microsecond or so.
 """
 
 from __future__ import annotations
@@ -86,6 +99,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.discovery import executors as _ex
@@ -134,8 +148,6 @@ class AdmissionStats:
     cands_considered_t0: int = 0  # (query, candidate) pairs swept by the
     #                               phase-0 signature gate
     cands_gated_t0: int = 0  # pairs the gate passed into the exact phases
-    signature_bytes: int = 0  # device-resident signature-tier bytes the
-    #                           most recent gated window swept
     host_syncs: int = 0      # device->host sync points paid by delivered
     #                          buckets (fused/dense/tiered: 1;
     #                          host-boundary two-phase: 2; fused overflow
@@ -172,7 +184,6 @@ class AdmissionStats:
                 self.cands_gated_t0 / self.cands_considered_t0
                 if self.cands_considered_t0 else None
             ),
-            "signature_bytes": self.signature_bytes,
             "host_syncs": self.host_syncs,
             # What the joinability gate saved: estimator work the dense
             # path would have paid for candidates min_join discards.
@@ -232,7 +243,7 @@ class _Window:
     __slots__ = (
         "queries", "jobs", "results", "outcomes", "C", "version",
         "top_k", "min_join", "min_containment", "rank", "isolate",
-        "use_pref", "n_shards", "leases",
+        "use_pref", "n_shards", "leases", "wid",
     )
 
     def __init__(self, queries: list, isolate: bool):
@@ -250,6 +261,7 @@ class _Window:
         self.use_pref = False
         self.n_shards = 1
         self.leases: list = []
+        self.wid = 0  # admission.submits at dispatch: the trace spans' id
 
     def release(self) -> None:
         """Release the window's plan leases (idempotent)."""
@@ -511,14 +523,17 @@ class DiscoveryService:
         min_containment: float = 0.0,
         rank: str = "mi",
     ) -> tuple[list, list]:
-        window = self._window_dispatch(
-            queries, top_k=top_k, min_join=min_join,
-            prefilter=prefilter, isolate=isolate, fused=fused,
-            min_containment=min_containment, rank=rank,
-        )
-        if window is None:
-            return [], []
-        return self._window_collect(window)
+        # The window id _window_dispatch is about to give this submit.
+        with TraceAnnotation("discovery.submit",
+                             window=self.admission.submits + 1):
+            window = self._window_dispatch(
+                queries, top_k=top_k, min_join=min_join,
+                prefilter=prefilter, isolate=isolate, fused=fused,
+                min_containment=min_containment, rank=rank,
+            )
+            if window is None:
+                return [], []
+            return self._window_collect(window)
 
     def _window_dispatch(
         self, queries: list[Sketch], *, top_k: int, min_join: int,
@@ -555,6 +570,7 @@ class DiscoveryService:
         st = self.admission
         st.submits += 1
         win = _Window(list(queries), isolate)
+        win.wid = st.submits
         results, outcomes = win.results, win.outcomes
 
         # 0. admission validation: quarantine sketches the pipeline
@@ -649,62 +665,65 @@ class DiscoveryService:
         # enqueued until its shortlist exists.  Stat deltas are *staged*
         # on the job and committed only after its collect succeeds.
         try:
-            for job in jobs:
+            for bi, job in enumerate(jobs):
                 job.rung = primary_rung
-                try:
-                    job.q_bucket = bucket_queries(
-                        len(job.chunk), self.max_q_bucket
-                    )
-                    job.sp = self.plan_cache.lookup(
-                        version, job.y_disc, job.q_bucket,
-                        lambda y=job.y_disc: self.index.plan(y, k=self.k),
-                        coalesced=coalesced,
-                    )
-                    job.staged = {
-                        "batches": 1,
-                        "padded_lanes": job.q_bucket - len(job.chunk),
-                        "q_buckets": {job.q_bucket},
-                        "host_syncs": 1,
-                    }
-                    job.sketches = [queries[i] for i in job.chunk]
-                    job.trains = _ex.stack_trains_host(job.sketches)
-                    if use_gate:
-                        # Tiered: the phase-0 containment sweep plus the
-                        # whole fused pipeline in one dispatch; the
-                        # bucket's only host sync is still its collect.
-                        job.handle = self._tiered_dispatch(
-                            job, min_join, min_containment, top_k,
-                            n_shards, C, version,
+                with TraceAnnotation("discovery.dispatch", window=win.wid,
+                                     bucket=bi):
+                    try:
+                        job.q_bucket = bucket_queries(
+                            len(job.chunk), self.max_q_bucket
                         )
-                    elif use_fused:
-                        # Fused two-phase: the whole prefilter ->
-                        # compact -> gather -> score pipeline is
-                        # enqueued here; the bucket's only host sync is
-                        # its collect.
-                        job.handle = self._fused_dispatch(
-                            job, min_join, top_k, n_shards, C, version
+                        job.sp = self.plan_cache.lookup(
+                            version, job.y_disc, job.q_bucket,
+                            lambda y=job.y_disc: self.index.plan(
+                                y, k=self.k),
+                            coalesced=coalesced,
                         )
-                    elif use_pref:
-                        ex = self._dist if self._dist is not None \
-                            else self._batched
-                        job.pend1 = ex.prefilter_dispatch(
-                            job.sp.plan, job.trains, q_bucket=job.q_bucket
-                        )
-                    elif self._dist is not None:
-                        want = topk_oversample(top_k, C)
-                        job.handle = self._dist.topk_dispatch(
-                            job.sp.plan, job.trains, want,
-                            q_bucket=job.q_bucket,
-                        )
-                    else:
-                        job.handle = self._batched.dispatch(
-                            job.sp.plan, job.trains, q_bucket=job.q_bucket
-                        )
-                except Exception as e:  # noqa: BLE001 — bucket-isolated
-                    job.error = e
-                    if not isolate:
-                        st.failed_buckets += 1
-                        raise
+                        job.staged = {
+                            "batches": 1,
+                            "padded_lanes": job.q_bucket - len(job.chunk),
+                            "q_buckets": {job.q_bucket},
+                            "host_syncs": 1,
+                        }
+                        job.sketches = [queries[i] for i in job.chunk]
+                        job.trains = _ex.stack_trains_host(job.sketches)
+                        if use_gate:
+                            # Tiered: the phase-0 containment sweep plus the
+                            # whole fused pipeline in one dispatch; the
+                            # bucket's only host sync is still its collect.
+                            job.handle = self._tiered_dispatch(
+                                job, min_join, min_containment, top_k,
+                                n_shards, C, version,
+                            )
+                        elif use_fused:
+                            # Fused two-phase: the whole prefilter ->
+                            # compact -> gather -> score pipeline is
+                            # enqueued here; the bucket's only host sync is
+                            # its collect.
+                            job.handle = self._fused_dispatch(
+                                job, min_join, top_k, n_shards, C, version
+                            )
+                        elif use_pref:
+                            ex = self._dist if self._dist is not None \
+                                else self._batched
+                            job.pend1 = ex.prefilter_dispatch(
+                                job.sp.plan, job.trains, q_bucket=job.q_bucket
+                            )
+                        elif self._dist is not None:
+                            want = topk_oversample(top_k, C)
+                            job.handle = self._dist.topk_dispatch(
+                                job.sp.plan, job.trains, want,
+                                q_bucket=job.q_bucket,
+                            )
+                        else:
+                            job.handle = self._batched.dispatch(
+                                job.sp.plan, job.trains, q_bucket=job.q_bucket
+                            )
+                    except Exception as e:  # noqa: BLE001 — bucket-isolated
+                        job.error = e
+                        if not isolate:
+                            st.failed_buckets += 1
+                            raise
 
             # 2b. host-boundary two-phase buckets only: collect join
             # sizes, build shortlists, and dispatch phase 2 for every
@@ -713,13 +732,15 @@ class DiscoveryService:
             # Fused buckets were fully enqueued in step 2 and skip this
             # phase entirely.
             if use_pref and not use_fused:
-                for job in jobs:
+                for bi, job in enumerate(jobs):
                     if job.error is not None:
                         continue
                     try:
-                        job.handle = self._shortlist_phase(
-                            job, min_join, top_k, n_shards, C, version
-                        )
+                        with TraceAnnotation("discovery.dispatch",
+                                             window=win.wid, bucket=bi):
+                            job.handle = self._shortlist_phase(
+                                job, min_join, top_k, n_shards, C, version
+                            )
                     except Exception as e:  # noqa: BLE001
                         job.error = e
                         if not isolate:
@@ -747,22 +768,25 @@ class DiscoveryService:
             # 3. collect (first host sync of each handle's result set),
             # fence, rank, scatter to arrival order, and only then
             # commit the bucket's staged counters.
-            for job in win.jobs:
+            for bi, job in enumerate(win.jobs):
                 if job.error is not None:
                     continue
+                span = {"window": win.wid, "bucket": bi}
                 try:
-                    triples = self._collect_triples(
-                        job, C, min_join, top_k, n_shards, version,
-                        min_containment=win.min_containment,
-                    )
+                    with TraceAnnotation("discovery.collect", **span):
+                        triples = self._collect_triples(
+                            job, C, min_join, top_k, n_shards, version,
+                            min_containment=win.min_containment,
+                        )
                 except Exception as e:  # noqa: BLE001
                     job.error = e
                     if not isolate:
                         st.failed_buckets += 1
                         raise
                     continue
-                self._finish(job, triples, queries, results, outcomes,
-                             top_k, min_join, isolate, rank=rank, C=C)
+                with TraceAnnotation("discovery.rank", **span):
+                    self._finish(job, triples, queries, results, outcomes,
+                                 top_k, min_join, isolate, rank=rank, C=C)
 
             # 4. recovery (isolate mode): failed buckets retry with
             # backoff, then descend the executor ladder — *ungated*
@@ -891,8 +915,6 @@ class DiscoveryService:
         job.staged["s_buckets"] = {s for _, _, s in spec.signature}
         job.staged["fused_windows"] = 1
         job.staged["gated_windows"] = 1
-        job.staged["signature_bytes"] = \
-            self.index.ingest_stats["signature_bytes"]
         if on_mesh:
             return self._dist.tiered_topk_dispatch(
                 job.sp.plan, job.trains, tspec, spec, min_join,
@@ -950,7 +972,6 @@ class DiscoveryService:
                     )
                 job.staged["gated_windows"] = 0
                 job.staged.pop("cands_considered_t0", None)
-                job.staged.pop("signature_bytes", None)
                 job.handle = self._fused_dispatch(
                     job, min_join, top_k, n_shards, C, version
                 )
@@ -1060,8 +1081,6 @@ class DiscoveryService:
         st.gated_windows += staged.get("gated_windows", 0)
         st.cands_considered_t0 += staged.get("cands_considered_t0", 0)
         st.cands_gated_t0 += staged.get("cands_gated_t0", 0)
-        if "signature_bytes" in staged:
-            st.signature_bytes = staged["signature_bytes"]
 
     # ------------------------------------------------------------------
     # Recovery ladder

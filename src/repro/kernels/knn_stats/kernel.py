@@ -47,6 +47,11 @@ BLOCK = 256
 LANES = 128
 _BIG_LANE = 1 << 30  # python int: jnp constants would be captured as consts
 
+# The kernel's name on the device: every ``pallas_call`` below is named
+# ``KERNEL_NAME`` or ``KERNEL_NAME`` plus a suffix, so one substring
+# finds all of this kernel's device ops in a profiler trace.
+KERNEL_NAME = "knn_stats_radius_counts"
+
 
 
 # Extraction loops at most this long are unrolled; longer ones (the
@@ -425,6 +430,7 @@ def knn_smallest_padded(
     shape = jax.ShapeDtypeStruct((P, LANES), jnp.float32)
     return pl.pallas_call(
         functools.partial(_knn_kernel, bm=block, bn=block, k=k, mode=mode),
+        name=f"{KERNEL_NAME}_knn",
         grid=grid,
         in_specs=[col, row, col, row, col, row],
         out_specs=(out, out),
@@ -481,12 +487,14 @@ def ball_counts_padded(
     if which == "y":
         return pl.pallas_call(
             functools.partial(_counts_kernel_y, bm=block, bn=block),
+            name=f"{KERNEL_NAME}_xfree",
             in_specs=[col, row, col, row, col],
             **common,
         )(yc, yr, mc, mr, rc)
     xc, xr = x.reshape(P, 1), x.reshape(1, P)
     return pl.pallas_call(
         functools.partial(_counts_kernel, bm=block, bn=block),
+        name=f"{KERNEL_NAME}_balls",
         in_specs=[col, row, col, row, col, row, col],
         **common,
     )(xc, xr, yc, yr, mc, mr, rc)
@@ -550,6 +558,7 @@ def radius_counts_padded(
                 _radius_counts_kernel_1, bm=block, bn=block,
                 k=k, kb=kb, kk=kkv, mode=mode, which=which,
             ),
+            name=f"{KERNEL_NAME}_1tile",
             grid=(1, 1),
             in_specs=[col, row, col, row, col, row],
             **common,
@@ -563,6 +572,7 @@ def radius_counts_padded(
             _radius_counts_kernel, bm=block, bn=block, nj=nj,
             k=k, kb=kb, kk=kkv, mode=mode, which=which,
         ),
+        name=KERNEL_NAME,
         grid=(P // block, 2 * nj),
         in_specs=[col, row, col, row, col, row],
         scratch_shapes=[

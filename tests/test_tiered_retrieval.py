@@ -469,7 +469,7 @@ class TestOverflowProtocol:
         assert st2["gated_windows"] - st1["gated_windows"] == 1
         assert st2["cands_gated_t0"] >= MIN_SURVIVORS + 4
         assert 0.0 < st2["t0_selectivity"] <= 1.0
-        assert st2["signature_bytes"] > 0
+        assert svc.stats()["ingest"]["signature_bytes"] > 0
         assert _flat(cold[0]) == _flat(warm[0]) == _flat(plain[0])
 
     def test_tiered_dispatch_fault_recovers_ungated(self):
