@@ -27,27 +27,28 @@ sys.path.insert(0, os.path.join(ROOT, "bench"))
 def control(root: str, bench: dict, name: str, seed: int) -> dict:
     import numpy as np
 
-    from harness import cell, compare, reference, traffic
-    from harness.lake import Lake
+    from harness import cell, compare, traffic
 
     _, cfg, spec = cell.cell_spec(root, bench, name)
     n, k = int(cfg["n"]), int(cfg["k"])
     opts = traffic.serving_options(spec)
-    mj, top_k = opts["min_join"], opts["top_k"]
-    lake = Lake(cfg, seed)
+    mj, top_k, gates = opts["min_join"], opts["top_k"], traffic.gates(opts)
+    lake_mod = cell.load_lake(root, cfg)
+    lake_mod.check_options(gates)
+    lake = lake_mod.Lake(cfg, seed)
     lake.keep_rows()
     for a in range(0, lake.columns, cell.INGEST_CHUNK):
         lake.chunk(a, min(a + cell.INGEST_CHUNK, lake.columns))
-    ls = reference.LakeSketches(lake, n)
-    reqs = traffic.requests(lake, spec)[:int(spec["complete_checks"])]
+    ls = lake_mod.LakeSketches(lake, n)
+    reqs = lake_mod.requests(lake, spec)[:int(spec["complete_checks"])]
     joins: dict = {}
     gap, wrong, answers = 0.0, 0, 0
     for q in (q for r in reqs for q in r):
-        ref = reference.answer(lake, ls, q, n=n, k=k, min_join=mj,
-                               joins=joins)
-        low = reference.answer(lake, ls, q, n=n, k=k, min_join=mj,
-                               dtype="bfloat16", joins=joins)
-        served = [(f"c{c}", mi, js) for c, mi, js in reference.ranked(
+        ref = lake_mod.answer(lake, ls, q, n=n, k=k, min_join=mj,
+                              joins=joins, **gates)
+        low = lake_mod.answer(lake, ls, q, n=n, k=k, min_join=mj,
+                              dtype="bfloat16", joins=joins, **gates)
+        served = [(f"c{c}", mi, js) for c, mi, js in lake_mod.ranked(
             low, top_k=top_k, min_join=mj)]
         g, e = compare.check_answer(served, ref, top_k=top_k, min_join=mj)
         gap, wrong, answers = max(gap, g), wrong + e, answers + 1

@@ -1,10 +1,16 @@
 """One run of one cell: set-up, warm-up, the measured window, the check.
 
 ``run`` drives the discovery service as a user would: it ingests the
-seeded lake through ``DiscoveryService.add``, flushes it to the device,
+seeded lake one generated chunk per call, flushes it to the device,
 warms every shape the cell's traffic uses, measures for ``seconds``,
 reads the device's peak memory, frees the service and then holds the
 window's answers against the plain reference.
+
+A configuration states its deployment in data: the cell's ``chips``
+lay the service out (one chip, or a one-axis ``("data",)`` mesh over
+the first ``chips`` devices), and its ``"lake"`` names the module under
+``bench/lakes/`` that makes the lake, the mix's base tables and the
+reference (``table2`` where it names none).
 """
 
 from __future__ import annotations
@@ -21,8 +27,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from harness import compare, reference, tracing, traffic
-from harness.lake import Lake
+from harness import compare, tracing, traffic
 from harness.peaks import peaks
 
 INGEST_CHUNK = 2048
@@ -76,22 +81,46 @@ def cell_metrics(bench: dict, cell: dict, trace: bool) -> list[dict]:
     return [m for m in bench["per_layer"] if reports(m)]
 
 
-def load_reader(root: str, metric: str):
-    path = os.path.join(root, "bench", "metrics", metric + ".py")
+def load_module(root: str, folder: str, name: str):
+    """``bench/<folder>/<name>.py``, loaded by its path."""
+    path = os.path.join(root, "bench", folder, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(root: str, metric: str):
+    return load_module(root, "metrics", metric).read
+
+
+def load_lake(root: str, cfg: dict):
+    """The lake module the configuration names (``bench/lakes/``)."""
+    return load_module(root, "lakes", cfg.get("lake", "table2"))
 
 
 # ---------------------------------------------------------------------------
 # set-up
 # ---------------------------------------------------------------------------
 
-def ingest(svc, lake: Lake) -> float:
-    """Add every column of the lake; the next chunk is generated on a
-    second thread while this one is added."""
+def add_columns(svc, columns: list) -> None:
+    """Add ``columns``, each the arguments of one ``add``, in one call:
+    ``add_columns`` where the service has it, else ``add`` column by
+    column, in order.  The fallback goes once the service has
+    ``add_columns``."""
+    bulk = getattr(svc, "add_columns", None)
+    if bulk is not None:
+        bulk(columns)
+        return
+    for col in columns:
+        svc.add(*col)
+
+
+def ingest(svc, lake) -> float:
+    """Add every column of the lake, one call per generated chunk; the
+    next chunk is generated on a second thread while this one is
+    added."""
     C = lake.columns
     t = time.perf_counter()
     bounds = [(a, min(a + INGEST_CHUNK, C)) for a in range(0, C, INGEST_CHUNK)]
@@ -102,10 +131,10 @@ def ingest(svc, lake: Lake) -> float:
             if i + 1 < len(bounds):
                 nxt = pool.submit(lake.chunk, *bounds[i + 1])
             keys, vf, vc = ch["keys"], ch["vals_f"], ch["vals_c"]
-            for j, c in enumerate(range(a, b)):
-                disc = bool(lake.discrete[c])
-                svc.add(f"c{c}", "k", "v", keys[j], vc[j] if disc else vf[j],
-                        disc)
+            disc = lake.discrete[a:b].tolist()
+            add_columns(svc, [(f"c{c}", "k", "v", keys[j],
+                               vc[j] if disc[j] else vf[j], disc[j])
+                              for j, c in enumerate(range(a, b))])
     return time.perf_counter() - t
 
 
@@ -126,10 +155,18 @@ class Stage:
     """A service with the cell's lake ingested and its queries sketched."""
 
 
+def memory_peak(devices) -> int:
+    """Peak bytes in use on the fullest of ``devices`` (0 where the
+    backend keeps no count)."""
+    return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devices))
+
+
 def set_up(root: str, bench: dict, name: str, *, seed: int,
            require_chip: bool = True, service_factory=None) -> Stage:
-    """Everything before warm-up: device check, lake, ingest, flush, the
-    query sketches the traffic will send."""
+    """Everything before warm-up: device check, lake, the service on the
+    cell's chips, ingest, flush, the query sketches the traffic will
+    send."""
     import jax
     from repro.core.discovery import DiscoveryService
     from repro.core.sketch import build_sketch
@@ -151,12 +188,21 @@ def set_up(root: str, bench: dict, name: str, *, seed: int,
     st.seed = seed
     n = st.n = int(cfg["n"])
     st.opts = traffic.serving_options(spec)
+    st.lake_mod = lake_mod = load_lake(root, cfg)
+    lake_mod.check_options(traffic.gates(st.opts))
 
-    st.lake = Lake(cfg, seed)
+    st.lake = lake_mod.Lake(cfg, seed)
     st.lake.keep_rows()
+    chips = st.chips = int(st.cell["chips"])
+    layout = {}
+    if chips > 1:
+        from repro.parallel.compat import make_mesh
+        layout["mesh"] = make_mesh((chips,), ("data",),
+                                   devices=st.devices[:chips])
+        log(f"phase=mesh axis=data devices={chips}")
     make = service_factory or DiscoveryService
     st.svc = make(n=n, k=int(cfg["k"]), sig_width=int(cfg["sig_width"]),
-                  agg=cfg["agg"], method=cfg["method"])
+                  agg=cfg["agg"], method=cfg["method"], **layout)
     st.ingest_s = ingest(st.svc, st.lake)
     log(f"phase=ingest columns={len(st.svc)} seconds={st.ingest_s:.3f}")
     st.flush_s = flush(st.svc)
@@ -166,10 +212,10 @@ def set_up(root: str, bench: dict, name: str, *, seed: int,
         return build_sketch(q.keys, q.y, n=n, method=cfg["method"],
                             side="train", value_is_discrete=q.discrete)
 
-    st.requests = traffic.requests(st.lake, spec)
+    st.requests = lake_mod.requests(st.lake, spec)
     st.req_sk = [[sketch(q) for q in r] for r in st.requests]
     st.warm_sk = [[sketch(q) for q in r]
-                  for r in traffic.requests(st.lake, spec, warm=True)[:4]]
+                  for r in lake_mod.requests(st.lake, spec, warm=True)[:4]]
     return st
 
 
@@ -210,9 +256,7 @@ def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
           flush=True)
     log(f"phase=window compiles={window_compiles} seconds={window_s:.3f}")
 
-    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for d in st.devices[:int(cell["chips"])])
-    device["memory_peak_bytes"] = int(peak)
+    device["memory_peak_bytes"] = memory_peak(st.devices[:st.chips])
     del svc, st.svc
     gc.collect()
 
@@ -225,7 +269,11 @@ def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
                "screen_pairs_per_s": {
                    "value": pairs / (out["t_end"] - out["t0"]),
                    "unit": "pairs/s"}}
-    log(f"phase=screen requests={len(done)} pairs={pairs}")
+    req_s = np.asarray(out["request_s"])
+    log(f"phase=screen requests={len(done)} pairs={pairs} "
+        f"request_s_min={req_s.min():.4f} "
+        f"request_s_median={np.median(req_s):.4f} "
+        f"request_s_max={req_s.max():.4f}")
 
     # -- the check --------------------------------------------------------
     t_ref = time.perf_counter()
@@ -277,7 +325,8 @@ def check(st: Stage, done: list, count_lanes: bool):
     Returns (correct, checks, kNN lanes by estimator kind or None)."""
     lake, spec, n, k = st.lake, st.spec, st.n, int(st.cfg["k"])
     mj, top_k = st.opts["min_join"], st.opts["top_k"]
-    ls = reference.LakeSketches(lake, n)
+    lake_mod, gates = st.lake_mod, traffic.gates(st.opts)
+    ls = lake_mod.LakeSketches(lake, n)
     joins: dict = {}
     R = len(st.requests)
     rng = np.random.default_rng([int(st.seed) % (1 << 63), 7])
@@ -287,9 +336,10 @@ def check(st: Stage, done: list, count_lanes: bool):
     gap, wrong, entries = 0.0, 0, 0
     for i, (req, res) in enumerate(done):
         for q, served in zip(st.requests[req % R], res):
-            only = None if i in full else                 [compare.column_of(t) for t, _, _ in served]
-            ans = reference.answer(lake, ls, q, n=n, k=k, min_join=mj,
-                                   only=only, joins=joins)
+            only = None if i in full else \
+                [compare.column_of(t) for t, _, _ in served]
+            ans = lake_mod.answer(lake, ls, q, n=n, k=k, min_join=mj,
+                                  only=only, joins=joins, **gates)
             if ans.skipped:
                 continue
             g, w = compare.check_answer(served, ans, top_k=top_k,
@@ -300,9 +350,9 @@ def check(st: Stage, done: list, count_lanes: bool):
         lanes = {}
         for req, _ in done:
             for q in st.requests[req % R]:
-                _, _, _, js = reference.join(ls, q, n, joins)
+                _, _, _, js = lake_mod.join(ls, q, n, joins)
                 for disc in (False, True):
-                    kind = reference.kind_of(disc, q.discrete)
+                    kind = lake_mod.kind_of(disc, q.discrete)
                     cnt = int(((js >= mj) & (lake.discrete == disc)).sum())
                     lanes[kind] = lanes.get(kind, 0) + cnt
     ok, checks = compare.verdict(gap, wrong)

@@ -11,6 +11,9 @@ key count, that count uniform between the configuration's
 categorical as the mix lists them, each related to the lake's latent
 by its own strength, uniform in (0, 1).  ``distinct_requests`` base
 tables are built at set-up and sent in turn; warm-up sends others.
+A mix may also set the service's gates (``GATES``); one that departs
+from the service's default the harness passes to ``submit`` and to the
+lake's reference alike.
 
 Per seed the work is one multiset in another order: the key counts and
 strengths are evenly spaced quantiles, placed by the seed.
@@ -28,6 +31,11 @@ from harness.lake import Lake, Query, permutation, quantiles
 
 _WARM_TABLE = 1 << 20      # warm-up base tables
 
+# Serving options of ``DiscoveryService.submit`` that change its answers,
+# which a mix may set besides ``top_k`` and ``min_join``, with the
+# service's defaults.
+GATES = {"min_containment": 0.0}
+
 
 def load(path: str) -> dict:
     with open(path) as f:
@@ -36,11 +44,22 @@ def load(path: str) -> dict:
         raise ValueError(f"{path}: kind must be 'closed'")
     if not set(spec["targets"]) <= {"numeric", "categorical"}:
         raise ValueError(f"{path}: targets are 'numeric' or 'categorical'")
+    if not 0.0 <= float(spec.get("min_containment", 0.0)) <= 1.0:
+        raise ValueError(f"{path}: min_containment lies in [0, 1]")
     return spec
 
 
 def serving_options(spec: dict) -> dict:
-    return {"top_k": int(spec["top_k"]), "min_join": int(spec["min_join"])}
+    opts = {"top_k": int(spec["top_k"]), "min_join": int(spec["min_join"])}
+    opts.update((g, spec[g]) for g, default in GATES.items()
+                if spec.get(g, default) != default)
+    return opts
+
+
+def gates(opts: dict) -> dict:
+    """The gates among serving options ``opts``: those that depart from
+    the service's defaults."""
+    return {g: opts[g] for g in GATES if g in opts}
 
 
 def requests(lake: Lake, spec: dict, warm: bool = False) -> list[list[Query]]:
@@ -60,18 +79,20 @@ def requests(lake: Lake, spec: dict, warm: bool = False) -> list[list[Query]]:
 
 def run_closed(submit, sketches: list, seconds: float) -> dict:
     """Closed loop: ``submit(sketches) -> results`` until ``seconds``
-    have passed; the window ends at the first completion after that."""
-    done = []
-    t0 = time.perf_counter()
+    have passed; the window ends at the first completion after that.
+    ``request_s`` holds each request's time, submit to completion."""
+    done, request_s = [], []
+    t0 = t_end = time.perf_counter()
     i = 0
     while True:
         res = submit(sketches[i % len(sketches)])
         done.append((i, res))
         i += 1
-        t_end = time.perf_counter()
+        t_prev, t_end = t_end, time.perf_counter()
+        request_s.append(t_end - t_prev)
         if t_end - t0 >= seconds:
             break
-    return {"t0": t0, "t_end": t_end, "done": done}
+    return {"t0": t0, "t_end": t_end, "done": done, "request_s": request_s}
 
 
 class Compiles:

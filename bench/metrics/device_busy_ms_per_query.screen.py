@@ -1,6 +1,7 @@
-"""Device milliseconds per query served in the traced window: the union
-of the intervals in which an operation ran on the chip, over the
-queries served (no program names, so a refactor cannot silence it)."""
+"""Device milliseconds per query served in the traced window, per chip:
+on each chip the union of the intervals in which an operation ran,
+averaged over the cell's chips (``tracing.reduce``), over the queries
+served (no program names, so a refactor cannot silence it)."""
 
 
 def read(ctx):

@@ -1,4 +1,6 @@
-"""Share of the traced window in which no operation ran on the chip."""
+"""Share of the traced window in which no operation ran on a chip, per
+chip: one minus the busy time averaged over the cell's chips
+(``tracing.reduce``) over the window."""
 
 
 def read(ctx):

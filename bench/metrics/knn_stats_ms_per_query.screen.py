@@ -1,4 +1,5 @@
-"""Device milliseconds per query of the kNN estimator kernel: the ops
+"""Device milliseconds per query of the kNN estimator kernel, per chip
+(the union on each chip, averaged over the cell's chips): the ops
 whose own name is one of its ``pallas_call`` names
 (``kernels/knn_stats/kernel.py``: ``knn_stats_radius_counts`` and its
 suffixed variants).  The name is matched at the start of the op's text

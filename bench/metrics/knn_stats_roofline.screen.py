@@ -1,7 +1,8 @@
 """Share of the chip's roofline reached by the kNN estimator kernel
-(``kernels/knn_stats``): the least time the traffic's required kNN work
-could take at the published peaks (``harness.work``, counted from the
-lake the benchmark made), over the kernel's device time in the trace."""
+(``kernels/knn_stats``), per chip: the least time the traffic's required
+kNN work could take at one chip's published peaks (``harness.work``,
+counted from the lake the benchmark made), over the kernel's device
+time in the trace summed over the cell's chips."""
 
 from harness import tracing, work
 

@@ -144,3 +144,51 @@ def test_reader_without_a_device_plane_reads_nothing(tmp_path, readers,
                                                      metric):
     host_only = XSPACE[XSPACE.index("planes {\n  id: 3"):]
     assert readers[metric](_ctx(tmp_path, host_only)) is None
+
+
+def _four_chips() -> str:
+    """Four chips; chip i (0-3) runs a fusion over [0, 100(i+1)) ns and
+    inside it a kernel call over [0, 50(i+1)) ns."""
+    planes = []
+    for i in range(4):
+        planes.append(f"""
+planes {{
+  id: {i + 1}
+  name: "/device:TPU:{i}"
+  lines {{
+    id: 1
+    name: "XLA Ops"
+    timestamp_ns: 1000
+    events {{ metadata_id: 1 offset_ps: 0 duration_ps: {100000 * (i + 1)} }}
+    events {{ metadata_id: 2 offset_ps: 0 duration_ps: {50000 * (i + 1)} }}
+  }}
+  event_metadata {{ key: 1 value {{ id: 1 name: "%fusion.1 = f32[8] fusion(f32[8] %p)" }} }}
+  event_metadata {{ key: 2 value {{ id: 2 name: "%knn_stats_radius_counts.3 = f32[8] custom-call(f32[8] %p), custom_call_target=\\"tpu_custom_call\\"" }} }}
+}}""")
+    return "".join(planes)
+
+
+def test_readers_on_four_chips_read_per_chip(tmp_path):
+    from harness import peaks, work
+    ctx = _ctx(tmp_path, _four_chips())
+    assert ctx["trace"]["chips"] == 4
+    # Busy: the union on each chip (100..400 ns), averaged over chips.
+    assert ctx["trace"]["busy_s"] == pytest.approx(250e-9)
+    pk = peaks.peaks("TPU v5 lite")
+    lanes = {"mixed": 3, "dc_x": 2}
+    ctx.update(window_s=1000e-9, knn_lanes=lanes, P=256, peaks=pk)
+    read = {m: cell.load_reader(str(tinybench.ROOT), m)(ctx) for m in (
+        "device_busy_ms_per_query.screen", "device_idle_pct.screen",
+        "knn_stats_ms_per_query.screen", "knn_stats_roofline.screen")}
+    assert read["device_busy_ms_per_query.screen"] == \
+        pytest.approx(250e-6 / QUERIES)
+    assert read["device_idle_pct.screen"] == pytest.approx(75.0)
+    # The kernel: 50..200 ns a chip, averaged.
+    assert read["knn_stats_ms_per_query.screen"] == \
+        pytest.approx(125e-6 / QUERIES)
+    # The work of all chips over the kernel time of all chips (500 ns) at
+    # one chip's peaks: the mean share of a chip.
+    assert read["knn_stats_roofline.screen"] == pytest.approx(
+        work.roofline_pct(lanes, 256, 500e-9, pk))
+    one = work.roofline_pct(lanes, 256, 125e-9, pk) / 4
+    assert read["knn_stats_roofline.screen"] == pytest.approx(one)

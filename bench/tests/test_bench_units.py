@@ -1,8 +1,12 @@
-"""The trace reduction, the kernel work count and the peaks table."""
+"""The trace reduction, the kernel work count, the peaks table, the
+memory peak over a cell's chips and the ingest loop."""
 
+import json
+
+import numpy as np
 import pytest
 
-import tinybench  # noqa: F401  (puts bench/ and src/ on the path)
+import tinybench  # puts bench/ and src/ on the path
 from harness import peaks, tracing, work
 
 
@@ -99,3 +103,54 @@ def test_kernel_work_count():
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError):
         peaks.peaks("TPU v99")
+
+
+class _Device:
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_memory_peak_is_the_fullest_chip():
+    from harness import cell
+    chips = [_Device({"peak_bytes_in_use": b}) for b in (5, 9, 7, 1)]
+    assert cell.memory_peak(chips) == 9
+    assert cell.memory_peak([_Device(None), _Device({})]) == 0
+
+
+class _ColumnByColumn:
+    def __init__(self):
+        self.calls = []
+
+    def add(self, *column):
+        self.calls.append(("add", column))
+
+
+class _Bulk(_ColumnByColumn):
+    def add_columns(self, columns):
+        self.calls.append(("add_columns", list(columns)))
+
+
+def test_ingest_makes_one_call_per_chunk_with_the_adds_in_order():
+    from harness import cell
+    from harness.lake import Lake
+    cfg = dict(json.loads(
+        (tinybench.BENCH / "configs" / "opendata_n256.json").read_text()),
+        **tinybench.TINY)
+    cfg["tables"] = 2 * cell.INGEST_CHUNK + 5
+    loop, bulk = _ColumnByColumn(), _Bulk()
+    cell.ingest(loop, Lake(cfg, 7))
+    cell.ingest(bulk, Lake(cfg, 7))
+    assert [name for name, _ in bulk.calls] == ["add_columns"] * 3
+    assert [len(cols) for _, cols in bulk.calls] == \
+        [cell.INGEST_CHUNK, cell.INGEST_CHUNK, 5]
+    added = [col for _, cols in bulk.calls for col in cols]
+    assert len(added) == len(loop.calls) == cfg["tables"]
+    for col, (_, ref) in zip(added, loop.calls):
+        assert col[0:3] == ref[0:3] and col[5] is ref[5]
+        np.testing.assert_array_equal(col[3], ref[3])
+        np.testing.assert_array_equal(col[4], ref[4])
+    disc = Lake(cfg, 7).discrete
+    assert [col[5] for col in added] == disc.tolist()

@@ -4,11 +4,13 @@
 configuration, shrinks the screen mix, and writes a ``BENCHMARK.json``
 whose cells mirror the real ones on them.
 ``run_cell`` drives one run of a cell through the harness on the CPU
-(the harness's look for a chip skipped).
+(the harness's look for a chip skipped); ``recording`` makes a service
+factory whose services keep their answers.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import pathlib
 import shutil
@@ -53,3 +55,38 @@ def run_cell(root: pathlib.Path, name: str, *, seed: int = 2**31 + 3,
     return cell.run(str(root), bench, name, seed=seed, seconds=seconds,
                     trace=trace, t_start=time.perf_counter(),
                     require_chip=False, **kw)
+
+
+def request_key(sketches) -> str:
+    """A request's fingerprint: its query sketches' keys and values."""
+    h = hashlib.sha1()
+    for sk in sketches:
+        h.update(sk.key_hashes.tobytes())
+        h.update(sk.values.tobytes())
+    return h.hexdigest()
+
+
+def recording():
+    """(answers, factory): the factory's services keep in ``answers``,
+    by ``request_key``, the first answer to each request as lists of
+    (column, mi, join size); ``factory.layouts`` holds the mesh each
+    service was given (None for one chip)."""
+    from harness import compare
+    from repro.core.discovery import DiscoveryService
+
+    answers: dict = {}
+
+    class Recording(DiscoveryService):
+        def submit(self, queries, **kw):
+            res = super().submit(queries, **kw)
+            answers.setdefault(request_key(queries), [
+                [(compare.column_of(t), float(mi), int(js))
+                 for t, mi, js in r] for r in res])
+            return res
+
+    def factory(**kw):
+        factory.layouts.append(kw.get("mesh"))
+        return Recording(**kw)
+
+    factory.layouts = []
+    return answers, factory
