@@ -32,9 +32,25 @@ from harness.peaks import peaks
 
 INGEST_CHUNK = 2048
 
+# A run is stopped 360 s after it starts.  After ingest come the flush,
+# the query sketches, warm-up, the window and the reference check, about
+# as long again at today's cells (ingest ends about 80 s in, the result
+# at about 150 s); so ingest has to end within half of the limit.  A
+# service that ingests a lake too slowly for that (column by column
+# where the cell's lake needs a bulk path) stops the run within seconds,
+# with no result, instead of being killed at the limit.
+SETUP_DEADLINE_S = 180.0
+# Where ingest goes column by column, it projects its end at least this
+# often, also inside a chunk.
+PROJECT_EVERY_S = 30.0
+
 
 class NoChip(RuntimeError):
     pass
+
+
+class DeadlineMissed(RuntimeError):
+    """Ingest projects its end past ``SETUP_DEADLINE_S``."""
 
 
 def log(*parts) -> None:
@@ -104,38 +120,78 @@ def load_lake(root: str, cfg: dict):
 # set-up
 # ---------------------------------------------------------------------------
 
-def add_columns(svc, columns: list) -> None:
-    """Add ``columns``, each the arguments of one ``add``, in one call:
-    ``add_columns`` where the service has it, else ``add`` column by
-    column, in order.  The fallback goes once the service has
-    ``add_columns``."""
-    bulk = getattr(svc, "add_columns", None)
-    if bulk is not None:
-        bulk(columns)
-        return
-    for col in columns:
-        svc.add(*col)
+def ingest(svc, lake, devices=(), t_start: float | None = None) -> float:
+    """Add every column of the lake, one chunk of ``INGEST_CHUNK``
+    columns at a time; the next chunk is made on a second thread while
+    this one is added.  Chunk ``i`` is made on the cell's chip
+    ``devices[i % len(devices)]`` (``lake.chunk(c0, c1, device)``; a
+    host lake ignores the chip, and with no ``devices`` it is None).
 
+    A chunk goes whole to ``svc.add_columns`` where the service has it,
+    else to ``svc.add`` column by column, in order, from a host copy.
 
-def ingest(svc, lake) -> float:
-    """Add every column of the lake, one call per generated chunk; the
-    next chunk is generated on a second thread while this one is
-    added."""
+    After each chunk (and, column by column, at least every
+    ``PROJECT_EVERY_S``) ingest projects its end, and raises
+    ``DeadlineMissed`` where that lies more than ``SETUP_DEADLINE_S``
+    after ``t_start`` (the process start; ingest's own where None).
+    The first chunk on each chip compiles the lake's programs and the
+    service's, so the rate is taken from the first call to the service
+    after those chunks on: until then the projection is the time spent.
+    Returns ingest's seconds."""
     C = lake.columns
-    t = time.perf_counter()
+    t0 = last = time.perf_counter()
+    t_start = t0 if t_start is None else t_start
+    mark = None         # (time, columns) at the start of the steady rate
+    logged = False
+
+    def project(done: int, now: float) -> None:
+        nonlocal last, logged
+        last = now
+        end = now - t_start
+        if mark is not None and done > mark[1]:
+            end += (C - done) * (now - mark[0]) / (done - mark[1])
+        text = (f"projected_ingest_end_s={end:.1f} "
+                f"limit_s={SETUP_DEADLINE_S:.0f} columns={done}/{C} "
+                f"ingest_s={now - t0:.3f}")
+        if end > SETUP_DEADLINE_S:
+            raise DeadlineMissed(text)
+        if mark is not None and done > mark[1] and not logged:
+            logged = True
+            log(f"ingest {text}")
+
+    def chip(i: int):
+        return devices[i % len(devices)] if len(devices) else None
+
+    bulk = getattr(svc, "add_columns", None)
     bounds = [(a, min(a + INGEST_CHUNK, C)) for a in range(0, C, INGEST_CHUNK)]
     with ThreadPoolExecutor(1) as pool:
-        nxt = pool.submit(lake.chunk, *bounds[0])
+        nxt = pool.submit(lake.chunk, *bounds[0], chip(0))
         for i, (a, b) in enumerate(bounds):
             ch = nxt.result()
             if i + 1 < len(bounds):
-                nxt = pool.submit(lake.chunk, *bounds[i + 1])
-            keys, vf, vc = ch["keys"], ch["vals_f"], ch["vals_c"]
-            disc = lake.discrete[a:b].tolist()
-            add_columns(svc, [(f"c{c}", "k", "v", keys[j],
-                               vc[j] if disc[j] else vf[j], disc[j])
-                              for j, c in enumerate(range(a, b))])
-    return time.perf_counter() - t
+                nxt = pool.submit(lake.chunk, *bounds[i + 1], chip(i + 1))
+            warm = i + 1 >= max(1, len(devices))
+            tables = [f"c{c}" for c in range(a, b)]
+            if bulk is not None:
+                bulk(tables, "k", "v", ch["keys"], ch["vals_f"],
+                     ch["vals_c"], lake.discrete[a:b])
+            else:
+                keys, vf, vc = (np.asarray(ch[f])
+                                for f in ("keys", "vals_f", "vals_c"))
+                disc = lake.discrete[a:b].tolist()
+                for j, table in enumerate(tables):
+                    svc.add(table, "k", "v", keys[j],
+                            vc[j] if disc[j] else vf[j], disc[j])
+                    now = time.perf_counter()
+                    if warm and mark is None:
+                        mark = (now, a + j + 1)
+                    elif now - last >= PROJECT_EVERY_S:
+                        project(a + j + 1, now)
+            now = time.perf_counter()
+            if warm and mark is None:
+                mark = (now, b)
+            project(b, now)
+    return time.perf_counter() - t0
 
 
 def flush(svc) -> float:
@@ -162,7 +218,7 @@ def memory_peak(devices) -> int:
                    for d in devices))
 
 
-def set_up(root: str, bench: dict, name: str, *, seed: int,
+def set_up(root: str, bench: dict, name: str, *, seed: int, t_start: float,
            require_chip: bool = True, service_factory=None) -> Stage:
     """Everything before warm-up: device check, lake, the service on the
     cell's chips, ingest, flush, the query sketches the traffic will
@@ -191,9 +247,12 @@ def set_up(root: str, bench: dict, name: str, *, seed: int,
     st.lake_mod = lake_mod = load_lake(root, cfg)
     lake_mod.check_options(traffic.gates(st.opts))
 
+    chips = st.chips = int(st.cell["chips"])
+    t = time.perf_counter()
     st.lake = lake_mod.Lake(cfg, seed)
     st.lake.keep_rows()
-    chips = st.chips = int(st.cell["chips"])
+    log(f"phase=lake columns={st.lake.columns} "
+        f"seconds={time.perf_counter() - t:.3f}")
     layout = {}
     if chips > 1:
         from repro.parallel.compat import make_mesh
@@ -203,7 +262,7 @@ def set_up(root: str, bench: dict, name: str, *, seed: int,
     make = service_factory or DiscoveryService
     st.svc = make(n=n, k=int(cfg["k"]), sig_width=int(cfg["sig_width"]),
                   agg=cfg["agg"], method=cfg["method"], **layout)
-    st.ingest_s = ingest(st.svc, st.lake)
+    st.ingest_s = ingest(st.svc, st.lake, st.devices[:chips], t_start)
     log(f"phase=ingest columns={len(st.svc)} seconds={st.ingest_s:.3f}")
     st.flush_s = flush(st.svc)
     log(f"phase=flush seconds={st.flush_s:.3f}")
@@ -212,10 +271,12 @@ def set_up(root: str, bench: dict, name: str, *, seed: int,
         return build_sketch(q.keys, q.y, n=n, method=cfg["method"],
                             side="train", value_is_discrete=q.discrete)
 
+    t = time.perf_counter()
     st.requests = lake_mod.requests(st.lake, spec)
     st.req_sk = [[sketch(q) for q in r] for r in st.requests]
     st.warm_sk = [[sketch(q) for q in r]
                   for r in lake_mod.requests(st.lake, spec, warm=True)[:4]]
+    log(f"phase=sketches seconds={time.perf_counter() - t:.3f}")
     return st
 
 
@@ -231,14 +292,16 @@ def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
         service_factory=None) -> dict:
     import jax
 
-    st = set_up(root, bench, name, seed=seed, require_chip=require_chip,
-                service_factory=service_factory)
+    st = set_up(root, bench, name, seed=seed, t_start=t_start,
+                require_chip=require_chip, service_factory=service_factory)
     cell, cfg, spec, lake = st.cell, st.cfg, st.spec, st.lake
     svc, compiles, device, n = st.svc, st.compiles, st.device, st.n
+    t = time.perf_counter()
     calls = warm_up(st)
     t_window = time.perf_counter()
+    log(f"phase=warmup calls={calls} seconds={t_window - t:.3f}")
     setup_s = t_window - t_start
-    log(f"phase=setup seconds={setup_s:.3f} warmup_calls={calls}")
+    log(f"phase=setup seconds={setup_s:.3f}")
 
     # -- the measured window --------------------------------------------
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
@@ -276,9 +339,7 @@ def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
         f"request_s_max={req_s.max():.4f}")
 
     # -- the check --------------------------------------------------------
-    t_ref = time.perf_counter()
     ok, checks, lanes = check(st, done, trace)
-    log(f"phase=reference seconds={time.perf_counter() - t_ref:.3f}")
 
     result = {"correct": ok, "attempted": int(attempted), "failed": 0}
     wanted = cell_metrics(bench, cell, trace)
@@ -317,27 +378,45 @@ def run(root: str, bench: dict, name: str, *, seed: int, seconds: float,
 # the check
 # ---------------------------------------------------------------------------
 
+def complete_sample(seed: int, columns: int, size: int) -> np.ndarray:
+    """The columns, drawn from the seed, that a mix's complete checks
+    cover where it sets ``complete_columns`` (sorted)."""
+    rng = np.random.default_rng([int(seed) % (1 << 63), 11])
+    return np.sort(rng.choice(columns, size=min(columns, size),
+                              replace=False))
+
+
 def check(st: Stage, done: list, count_lanes: bool):
     """Hold the window's answers against the reference: every served
     entry of every completed request (its join size, its MI, the
     order and length of its list), and, on ``complete_checks`` requests
-    drawn from the seed, every passing column the list may have missed.
-    Returns (correct, checks, kNN lanes by estimator kind or None)."""
+    drawn from the seed, every passing column the list may have missed:
+    among all columns, or among the mix's ``complete_columns`` drawn
+    from the seed.  Returns (correct, checks, kNN lanes by estimator
+    kind or None)."""
     lake, spec, n, k = st.lake, st.spec, st.n, int(st.cfg["k"])
     mj, top_k = st.opts["min_join"], st.opts["top_k"]
     lake_mod, gates = st.lake_mod, traffic.gates(st.opts)
+    t = time.perf_counter()
     ls = lake_mod.LakeSketches(lake, n)
+    log(f"phase=reference_build seconds={time.perf_counter() - t:.3f}")
+    t = time.perf_counter()
     joins: dict = {}
     R = len(st.requests)
     rng = np.random.default_rng([int(st.seed) % (1 << 63), 7])
     full = set(rng.choice(len(done), size=min(len(done),
                           int(spec["complete_checks"])), replace=False)
                .tolist())
+    sample = None
+    if "complete_columns" in spec:
+        sample = complete_sample(st.seed, lake.columns,
+                                 int(spec["complete_columns"]))
     gap, wrong, entries = 0.0, 0, 0
     for i, (req, res) in enumerate(done):
         for q, served in zip(st.requests[req % R], res):
-            only = None if i in full else \
-                [compare.column_of(t) for t, _, _ in served]
+            only = [compare.column_of(meta) for meta, _, _ in served]
+            if i in full:
+                only = None if sample is None else np.union1d(sample, only)
             ans = lake_mod.answer(lake, ls, q, n=n, k=k, min_join=mj,
                                   only=only, joins=joins, **gates)
             if ans.skipped:
@@ -345,17 +424,20 @@ def check(st: Stage, done: list, count_lanes: bool):
             g, w = compare.check_answer(served, ans, top_k=top_k,
                                         min_join=mj)
             gap, wrong, entries = max(gap, g), wrong + w, entries + len(served)
+    log(f"phase=checks requests={len(done)} complete={len(full)} "
+        f"complete_columns={lake.columns if sample is None else len(sample)} "
+        f"served_entries={entries} seconds={time.perf_counter() - t:.3f}")
     lanes = None
     if count_lanes:
+        t = time.perf_counter()
         lanes = {}
         for req, _ in done:
             for q in st.requests[req % R]:
-                _, _, _, js = lake_mod.join(ls, q, n, joins)
+                js = lake_mod.join(ls, q, n, joins)[-1]
                 for disc in (False, True):
                     kind = lake_mod.kind_of(disc, q.discrete)
                     cnt = int(((js >= mj) & (lake.discrete == disc)).sum())
                     lanes[kind] = lanes.get(kind, 0) + cnt
+        log(f"phase=lanes seconds={time.perf_counter() - t:.3f}")
     ok, checks = compare.verdict(gap, wrong)
-    log(f"phase=check requests={len(done)} complete={len(full)} "
-        f"served_entries={entries}")
     return ok, checks, lanes

@@ -11,9 +11,12 @@ query by query.  Two numbers are compared, each with its limit:
 * ``wrong``: served entries the reference refuses (a join size that is
   not the exact one, a column below ``min_join``, a list out of order
   or longer than ``top_k``), lists shorter than the columns that pass
-  allow, and, on the answers the reference completes, passing columns
-  the list misses (a column whose MI beats the served list's last entry
-  by more than the ``mi_gap`` limit).  Exact: limit 0.
+  allow, and passing columns the list misses among those whose MI the
+  reference estimated (a column whose MI beats the served list's last
+  entry by more than the ``mi_gap`` limit): on the answers checked in
+  full, every column or the mix's seeded sample of them.  Exact: limit 0.
+
+The reference's answers are indexed by column number.
 """
 
 from __future__ import annotations
@@ -29,18 +32,23 @@ def column_of(meta) -> int:
     return int(getattr(meta, "table", meta)[1:])
 
 
+def _index(ans, c: int):
+    """Position of column ``c`` in the answer's arrays (None for no
+    column of the lake)."""
+    return c if 0 <= c < len(ans.js) else None
+
+
 def _errors(served: list, ans, *, top_k: int, min_join: int,
             tol: float) -> int:
-    pos = {int(c): i for i, c in enumerate(ans.cols)}
     errors = max(0, len(served) - top_k)
     prev = np.inf
-    seen = set()
+    seen = []
     for c, mi, js in served:
-        seen.add(c)
-        i = pos.get(c)
+        i = _index(ans, c)
         if i is None:           # no column of the lake
             errors += 1
             continue
+        seen.append(i)
         if ans.ambiguous[i]:
             continue
         if js != ans.js[i] or ans.js[i] < min_join:
@@ -51,11 +59,10 @@ def _errors(served: list, ans, *, top_k: int, min_join: int,
         prev = mi
     sure = (ans.js >= min_join) & ~ans.ambiguous
     errors += max(0, min(top_k, int(sure.sum())) - len(served))
-    if not ans.complete:        # MI known for the served columns only
-        return errors
-    sure &= ~np.isnan(ans.mi)
-    unserved = np.array([int(c) not in seen for c in ans.cols], bool)
-    cand = sure & unserved
+    # Passing columns the list misses, among those whose MI the answer
+    # holds (the served ones alone, a seeded sample, or all).
+    cand = sure & ~np.isnan(ans.mi)
+    cand[seen] = False
     if len(served) >= top_k:
         floor = min(mi for _, mi, _ in served)
         cand &= ans.mi > floor + tol
@@ -67,10 +74,9 @@ def check_answer(served: list, ans, *, top_k: int,
     """(mi_gap, wrong) of one served answer: ``served`` is the list of
     (candidate, mi, join size) the service returned."""
     items = [(column_of(t), float(mi), int(js)) for t, mi, js in served]
-    pos = {int(c): i for i, c in enumerate(ans.cols)}
     gap = 0.0
     for c, mi, _ in items:
-        i = pos.get(c)
+        i = _index(ans, c)
         if i is not None and not ans.ambiguous[i] and not np.isnan(ans.mi[i]):
             gap = max(gap, abs(mi - float(ans.mi[i])))
     wrong = _errors(items, ans, top_k=top_k, min_join=min_join,

@@ -188,9 +188,9 @@ class Lake:
         a = self.alpha[col]
         return a * self.latent(z) + (np.float32(1.0) - a) * noise
 
-    def chunk(self, c0: int, c1: int) -> dict:
+    def chunk(self, c0: int, c1: int, device=None) -> dict:
         """Columns [c0, c1) as (c1 - c0, rows) arrays: key hashes, float
-        and categorical values."""
+        and categorical values, made on the host whatever ``device``."""
         cols = np.arange(c0, c1)
         z = self.draw_keys(self._k_rows, cols, self.n_keys[c0:c1],
                            self.shift[c0:c1])

@@ -80,6 +80,18 @@ def train_rows(q: Query, n: int):
     return order[:n], bool(tie)
 
 
+def rank_order(U: int) -> tuple[np.ndarray, np.ndarray]:
+    """(pos, tied) of the key universe ``range(U)`` in a candidate
+    sketch's rank order: key ``z`` is ``pos[z]``-th; ``tied[p]`` where
+    the ``p``-th key's rank equals the next one's."""
+    rank = fib(murmur3_word(np.uint32(1), key_hash(np.arange(U))))
+    by_rank = np.argsort(rank, kind="stable")
+    pos = np.empty(U, np.int64)
+    pos[by_rank] = np.arange(U)
+    rank_sorted = rank[by_rank]
+    return pos, np.r_[rank_sorted[1:] == rank_sorted[:-1], False]
+
+
 class LakeSketches:
     """Reference candidate sketches of every column of the lake, as a
     (keys x columns) membership matrix ``member`` over the key universe,
@@ -88,13 +100,7 @@ class LakeSketches:
     def __init__(self, lake: Lake, n: int):
         U = lake.universe
         C = lake.columns
-        self.cols = np.arange(C)
-        rank = fib(murmur3_word(np.uint32(1), key_hash(np.arange(U))))
-        by_rank = np.argsort(rank, kind="stable")
-        self.pos = np.empty(U, np.int64)
-        self.pos[by_rank] = np.arange(U)
-        rank_sorted = rank[by_rank]
-        tied = np.r_[rank_sorted[1:] == rank_sorted[:-1], False]
+        self.pos, tied = rank_order(U)
         self.member = np.zeros((U, C), bool)
         self.ambiguous = np.zeros(C, bool)
         step = max(1, (1 << 24) // U)
@@ -114,6 +120,15 @@ class LakeSketches:
     def joined(self, z) -> np.ndarray:
         """(C, len(z)) whether each column's sketch holds each key."""
         return self.member[self.pos[z]].T
+
+
+def check_options(gates: dict) -> None:
+    """This reference (of the Table II lakes) has no phase-0 containment
+    gate, so it takes the service's defaults only."""
+    for key, value in gates.items():
+        raise ValueError(
+            f"lake table2 refuses {key}={value!r}: its reference has no "
+            f"phase-0 containment gate, so a mix on it leaves {key} out")
 
 
 def kind_of(cand_discrete: bool, target_discrete: bool) -> str:
@@ -253,22 +268,19 @@ def mi_lanes(x: np.ndarray, y: np.ndarray, mask: np.ndarray, *, kind: str,
 # ---------------------------------------------------------------------------
 
 class Answer:
-    """What the reference says of one query over the lake's columns.
+    """What the reference says of one query over the lake's columns,
+    each array indexed by column number.
 
-    ``cols`` are column numbers; ``js`` their exact join sizes; ``mi``
-    the estimate
-    of each column whose join reaches ``min_join`` (NaN otherwise, and
-    for the columns left out when ``complete`` is false);
-    ``ambiguous`` columns whose sketch is not defined (rank tie at the
-    sketch's edge), which no check holds to anything; ``skipped`` when
-    the query's own sketch is not defined, and the query goes
-    unchecked."""
+    ``js`` are the exact join sizes; ``mi`` the estimate of each column
+    whose join reaches ``min_join`` among the columns asked for (NaN
+    otherwise); ``ambiguous`` columns whose sketch is not defined (rank
+    tie at the sketch's edge), which no check holds to anything;
+    ``skipped`` when the query's own sketch is not defined, and the
+    query goes unchecked."""
 
-    def __init__(self, cols, js, mi, ambiguous, skipped: bool,
-                 complete: bool):
-        self.cols, self.js = cols, js
-        self.mi, self.ambiguous, self.skipped = mi, ambiguous, skipped
-        self.complete = complete
+    def __init__(self, js, mi, ambiguous, skipped: bool):
+        self.js, self.mi = js, mi
+        self.ambiguous, self.skipped = ambiguous, skipped
 
 
 def join(ls: LakeSketches, q: Query, n: int, joins: dict | None = None):
@@ -291,31 +303,34 @@ def answer(lake: Lake, ls: LakeSketches, q: Query, *, n: int, k: int,
     """The reference's answer to query ``q`` over the lake sketches
     ``ls`` (the base table holds at least ``n`` rows, so its sketch is
     full).  With ``only`` (column numbers), MI is estimated for those
-    columns alone: enough to hold a served list's entries, not to find
-    what it misses."""
+    columns alone: enough to hold a served list's entries, and to find
+    what it misses among them."""
     sel, tie, ins, js = join(ls, q, n, joins)
     tz, ty = q.z[sel], q.y[sel]
-    mi = np.full(len(ls.cols), np.nan, np.float32)
-    passing = np.flatnonzero(js >= min_join)
-    if only is not None:
-        passing = np.intersect1d(passing, np.asarray(only, np.int64))
-    for disc in (False, True):
-        sel_c = passing[lake.discrete[ls.cols[passing]] == disc]
-        if not len(sel_c):
-            continue
-        cols = ls.cols[sel_c]
+    mi = np.full(len(js), np.nan, np.float32)
+    for disc, cols in passing_columns(lake, js, min_join, only):
         x = lake.values(cols[:, None], tz[None, :])
         if disc:
             x = np.floor(np.float32(2.0) * x).astype(np.int64)
-        mi[sel_c] = mi_lanes(x, ty, ins[sel_c],
-                             kind=kind_of(disc, q.discrete), k=k, dtype=dtype)
-    return Answer(ls.cols, js, mi, ls.ambiguous.copy(), tie, only is None)
+        mi[cols] = mi_lanes(x, ty, ins[cols],
+                            kind=kind_of(disc, q.discrete), k=k, dtype=dtype)
+    return Answer(js, mi, ls.ambiguous.copy(), tie)
+
+
+def passing_columns(lake: Lake, js: np.ndarray, min_join: int, only=None):
+    """(discrete, column numbers) of the columns among ``only`` (all
+    where None) whose join reaches ``min_join``, numeric ones first;
+    a group with no column is left out."""
+    passing = np.flatnonzero(js >= min_join)
+    if only is not None:
+        passing = np.intersect1d(passing, np.asarray(only, np.int64))
+    return [(disc, cols) for disc in (False, True)
+            if len(cols := passing[lake.discrete[passing] == disc])]
 
 
 def ranked(ans: Answer, *, top_k: int, min_join: int) -> list:
     """The reference's own top-k as (column, mi, join size) triples."""
     ok = (ans.js >= min_join) & ~np.isnan(ans.mi)
     idx = np.flatnonzero(ok)
-    order = np.lexsort((ans.cols[idx], -ans.mi[idx]))[:top_k]
-    return [(int(ans.cols[i]), float(ans.mi[i]), int(ans.js[i]))
-            for i in idx[order]]
+    order = np.lexsort((idx, -ans.mi[idx]))[:top_k]
+    return [(int(i), float(ans.mi[i]), int(ans.js[i])) for i in idx[order]]

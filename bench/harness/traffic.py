@@ -13,7 +13,10 @@ by its own strength, uniform in (0, 1).  ``distinct_requests`` base
 tables are built at set-up and sent in turn; warm-up sends others.
 A mix may also set the service's gates (``GATES``); one that departs
 from the service's default the harness passes to ``submit`` and to the
-lake's reference alike.
+lake's reference alike.  ``complete_checks`` base tables drawn from the
+seed are checked for passing columns their lists miss: among every
+column of the lake, or, where the mix sets ``complete_columns``, among
+that many drawn from the seed.
 
 Per seed the work is one multiset in another order: the key counts and
 strengths are evenly spaced quantiles, placed by the seed.
@@ -46,6 +49,8 @@ def load(path: str) -> dict:
         raise ValueError(f"{path}: targets are 'numeric' or 'categorical'")
     if not 0.0 <= float(spec.get("min_containment", 0.0)) <= 1.0:
         raise ValueError(f"{path}: min_containment lies in [0, 1]")
+    if int(spec.get("complete_columns", 1)) < 1:
+        raise ValueError(f"{path}: complete_columns is at least 1")
     return spec
 
 

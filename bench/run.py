@@ -11,7 +11,9 @@ lines on standard error are the numbers the correctness check compared,
 each with its limit; the last line on standard output is the result.
 
 Exits 2, with no result, when JAX finds no TPU or fewer chips than the
-cell needs: a CPU timing is never reported.
+cell needs: a CPU timing is never reported.  Exits 3, with no result
+and a ``phase=deadline`` line, when ingest projects its end past the
+set-up deadline (``harness.cell.SETUP_DEADLINE_S``).
 """
 
 from __future__ import annotations
@@ -56,7 +58,7 @@ def main(argv=None) -> int:
     with open(bench_path) as f:
         bench = json.load(f)
     _environment()
-    from harness.cell import NoChip, log, run
+    from harness.cell import DeadlineMissed, NoChip, log, run
     try:
         result = run(ROOT, bench, args.workload, seed=args.seed,
                      seconds=args.seconds, trace=bool(args.trace),
@@ -64,6 +66,9 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"no chip: {e}", file=sys.stderr)
         return 2
+    except DeadlineMissed as e:
+        log(f"phase=deadline {e}")
+        return 3
     for key, c in result["checks"].items():
         log(f"check {key}={c['value']} limit={c['limit']}")
     print(json.dumps(result), flush=True)

@@ -129,8 +129,12 @@ class _ColumnByColumn:
 
 
 class _Bulk(_ColumnByColumn):
-    def add_columns(self, columns):
-        self.calls.append(("add_columns", list(columns)))
+    def add_columns(self, tables, key_column, value_column, keys, vals_f,
+                    vals_c, discrete):
+        self.calls.append(("add_columns", [
+            (t, key_column, value_column, keys[j],
+             vals_c[j] if discrete[j] else vals_f[j], bool(discrete[j]))
+            for j, t in enumerate(tables)]))
 
 
 def test_ingest_makes_one_call_per_chunk_with_the_adds_in_order():
